@@ -9,6 +9,9 @@ from __future__ import annotations
 import argparse
 import sys
 import time
+from pathlib import Path
+
+from repro.compile_cache import enable_compile_cache
 
 
 def main(argv=None) -> None:
@@ -49,6 +52,7 @@ def main(argv=None) -> None:
                         "exit nonzero if the measured path regresses below "
                         "its floor")
     args = p.parse_args(argv)
+    enable_compile_cache(Path(__file__).resolve().parent.parent / ".jax_cache")
 
     if args.engine:
         from benchmarks.engine_bench import run_engine_bench
@@ -86,6 +90,7 @@ def main(argv=None) -> None:
         F.SMALL = dict(n_nodes=2_000, n_edges=12_000)
 
     rows = []
+    failed = []
 
     def out(res):
         rows.append(res)
@@ -98,9 +103,12 @@ def main(argv=None) -> None:
         print(f"== {fn.__name__}", flush=True)
         try:
             fn(out)
-        except Exception as e:  # keep the harness going; report at the end
+        except Exception as e:  # keep the harness going; fail at the end
             print(f"{fn.__name__},FAILED,{type(e).__name__}: {e}", flush=True)
+            failed.append(fn.__name__)
     print(f"\n{len(rows)} rows in {time.time()-t0:.0f}s")
+    if failed:
+        sys.exit(f"failed figures: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -289,7 +289,7 @@ def _backend_rows(quick: bool) -> dict:
                 "ingest_events_per_s": ing["events_per_s"],
                 "read_events_per_s": rd["events_per_s"],
             }
-        except Exception as e:  # noqa: BLE001 — record, keep the bench going
+        except Exception as e:  # noqa: BLE001 — record, fail at the end
             rows[backend] = {"error": f"{type(e).__name__}: {e}"}
         print(f"streaming/backends[{backend}]: {rows[backend]}", flush=True)
     return rows
@@ -416,6 +416,10 @@ def run_streaming_bench(quick: bool = False, check: bool = False,
             {"path": "sparse_vs_dense.ratio_0_001.speedup",
              "floor": 1.3 if quick else 5.0},
         ], baselines=view, section="streaming", label="streaming")
+    failed = sorted(b for b, row in report["backends"].items()
+                    if "error" in row)
+    if failed:
+        raise SystemExit(f"streaming: backend rows failed: {failed}")
     return report
 
 

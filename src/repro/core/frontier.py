@@ -179,6 +179,19 @@ def _pack_active(keys: np.ndarray, n_levels: int, n_units: int,
     return tuple(out)
 
 
+def _block_spans(d_s: np.ndarray, b_s: np.ndarray, nb: int):
+    """Per destination (ascending ids): the first and one-past-last edge
+    block holding any of its slots. A slot patch claims a free slot anywhere
+    in the destination's row tile, so a patched level is not sorted by
+    destination and the first/last occurrence of a destination need not
+    bound its span."""
+    key = np.unique(d_s * np.int64(nb) + b_s)
+    d_k = key // nb
+    uniq_d, first = np.unique(d_k, return_index=True)
+    last = np.concatenate([first[1:], [len(d_k)]]) - 1
+    return uniq_d, key[first] % nb, key[last] % nb + 1
+
+
 @dataclasses.dataclass
 class FrontierIndex:
     """Writer-row -> per-level push-block reachability (see module doc)."""
@@ -270,12 +283,8 @@ class FrontierIndex:
                 ent_lo.append(lo_b)
                 ent_hi.append(lo_b + 1)
             else:
-                # per-destination block span at this level: slots are sorted
-                # by destination, so first/last occurrence bound the span
-                uniq_d, first = np.unique(d_s, return_index=True)
-                last = np.concatenate([first[1:], [len(d_s)]]) - 1
-                lo_of = b_s[first]
-                hi_of = b_s[last] + 1
+                # per-destination block span at this level
+                uniq_d, lo_of, hi_of = _block_spans(d_s, b_s, nb)
                 pos = np.searchsorted(uniq_d, d_u)
                 ent_w.append(w_u)
                 ent_l.append(np.full(len(w_u), l, np.int32))
@@ -558,10 +567,7 @@ class ReaderFrontierIndex:
             if sl.size == 0:
                 continue
             d_s = seg[l][sl].astype(np.int64)
-            b_s = sl // E_BLK
-            uniq_d, first = np.unique(d_s, return_index=True)
-            last = np.concatenate([first[1:], [len(d_s)]]) - 1
-            for d, lo, hi in zip(uniq_d, b_s[first], b_s[last] + 1):
+            for d, lo, hi in zip(*_block_spans(d_s, sl // E_BLK, nb)):
                 for v in above.get(int(d), ()):
                     pull.setdefault(v, set()).update(
                         l * nb + b for b in range(int(lo), int(hi)))

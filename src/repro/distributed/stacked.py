@@ -36,7 +36,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.aggregates import Aggregate
@@ -87,8 +86,8 @@ def _run_stacked(mesh, body, args):
         return jax.tree.map(lambda x: x[None], out)
 
     specs = jax.tree.map(lambda _: P(SHARD_AXIS), args)
-    return shard_map(dev_body, mesh=mesh, in_specs=specs,
-                     out_specs=P(SHARD_AXIS), check_rep=False)(*args)
+    return jax.shard_map(dev_body, mesh=mesh, in_specs=specs,
+                         out_specs=P(SHARD_AXIS), check_vma=False)(*args)
 
 
 # ------------------------------------------------------------- jit programs
@@ -235,10 +234,10 @@ def _stacked_patch(mesh, arrays, prog, flags):
 
     arr_specs = jax.tree.map(lambda _: P(SHARD_AXIS), arrays)
     prog_specs = jax.tree.map(lambda _: P(), prog)
-    return shard_map(dev_body, mesh=mesh,
-                     in_specs=(arr_specs, prog_specs, P(SHARD_AXIS)),
-                     out_specs=P(SHARD_AXIS),
-                     check_rep=False)(arrays, prog, flags)
+    return jax.shard_map(dev_body, mesh=mesh,
+                         in_specs=(arr_specs, prog_specs, P(SHARD_AXIS)),
+                         out_specs=P(SHARD_AXIS),
+                         check_vma=False)(arrays, prog, flags)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))

@@ -95,8 +95,7 @@ def _run(plan: SegmentPlan, x: jnp.ndarray, op: str, interpret: bool) -> jnp.nda
         jnp.asarray(plan.seg_padded),
         jnp.asarray(plan.tile_of_block),
         jnp.asarray(plan.first_of_tile),
-        n_row_tiles=plan.n_row_tiles,
-        n_feat_tiles=f_pad // F_BLK,
+        jnp.zeros((plan.n_row_tiles * R_BLK, f_pad), jnp.float32),
         op=op,
         interpret=interpret,
     )
@@ -326,6 +325,41 @@ def make_leveled_plan(segs: list[np.ndarray], n_rows: int, *,
     )
 
 
+# Padded edge values (E_BLK x F_pad lanes per block) one kernel call streams:
+# a level of a 1M-node / 10M-edge overlay holds 65,536 blocks, whose f32
+# (e_pad, 128) buffer alone is 8.6 GB of a v5e's 16 GB HBM, so larger levels
+# are reduced in consecutive block chunks into one aliased accumulator.
+CHUNK_BYTES = 1 << 29
+
+
+def _reduce_blocks(x, seg, tob, fot, *, n_rows: int, n_row_tiles: int,
+                   op: str, interpret: bool, bf16: bool) -> jnp.ndarray:
+    """Kernel over the edge blocks of ``x`` (n_blocks * E_BLK, F) in chunks
+    of at most ``CHUNK_BYTES`` of padded values; returns (n_rows, F)."""
+    n_blocks = tob.shape[0]
+    F = x.shape[1]
+    f_pad = -(-F // F_BLK) * F_BLK
+    dt = jnp.bfloat16 if bf16 else jnp.float32
+    fit = max(1, CHUNK_BYTES // (E_BLK * f_pad * jnp.dtype(dt).itemsize))
+    c = min(n_blocks & -n_blocks, 1 << (fit.bit_length() - 1))
+
+    def chunk(i, acc):
+        take = lambda a, n: jax.lax.dynamic_slice_in_dim(a, i * n, n)
+        xc = jnp.pad(take(x, c * E_BLK).astype(dt), ((0, 0), (0, f_pad - F)))
+        return segment_agg_call(xc, take(seg, c * E_BLK), take(tob, c),
+                                take(fot, c), acc, op=op, interpret=interpret)
+
+    acc = jnp.zeros((n_row_tiles * R_BLK, f_pad), jnp.float32)
+    if n_blocks == c:
+        acc = chunk(0, acc)
+    else:
+        acc = jax.lax.fori_loop(0, n_blocks // c, chunk, acc)
+    # through the transpose: a plain [:n_rows, :F] slice keeps the
+    # accumulator's (8, 128) tiling, and on TPU every (n_rows, F) array fed
+    # by it (the level-loop carry) then pads F to 128 lanes in HBM
+    return acc.T[:F, :n_rows].T
+
+
 def segment_agg_level(x: jnp.ndarray, seg: jnp.ndarray, tob: jnp.ndarray,
                       fot: jnp.ndarray, *, n_rows: int, n_row_tiles: int,
                       op: str = "sum", interpret: bool = True,
@@ -336,21 +370,14 @@ def segment_agg_level(x: jnp.ndarray, seg: jnp.ndarray, tob: jnp.ndarray,
     (use ``LeveledPlan.layout`` for static companions or gather through a
     laid-out source-index array for runtime values). All arguments may be
     traced — in particular slices of the stacked tables inside a loop over
-    levels. Returns (n_rows, F); rows the level never touches are whatever the
-    kernel initialized them to, so callers mask by their own touched set.
+    levels. Returns (n_rows, F); rows the level never touches carry no
+    result, so callers mask by their own touched set.
     ``bf16`` streams edge values into VMEM as bfloat16 (2x block headroom);
     the kernels cast per block and accumulate in fp32 either way.
     """
-    F = x.shape[1]
-    f_pad = -(-F // F_BLK) * F_BLK
-    dt = jnp.bfloat16 if bf16 else jnp.float32
-    xf = jnp.pad(x.astype(dt), ((0, 0), (0, f_pad - F)))
-    out = segment_agg_call(
-        xf, seg, tob, fot,
-        n_row_tiles=n_row_tiles, n_feat_tiles=f_pad // F_BLK,
-        op=op, interpret=interpret,
-    )
-    return out[:n_rows, :F]
+    return _reduce_blocks(x, seg, tob, fot, n_rows=n_rows,
+                          n_row_tiles=n_row_tiles, op=op,
+                          interpret=interpret, bf16=bf16)
 
 
 def segment_agg_active(x: jnp.ndarray, seg: jnp.ndarray, tob: jnp.ndarray, *,
@@ -366,17 +393,10 @@ def segment_agg_active(x: jnp.ndarray, seg: jnp.ndarray, tob: jnp.ndarray, *,
     the grid (which is sized from ``x``) simply shrinks to K blocks. The
     first-of-tile flags are recomputed from the compacted ``tob`` (a tile's
     first *active* block initializes it). Output rows in tiles with no active
-    block are uninitialized — callers mask by the active destination set.
+    block carry no result — callers mask by the active destination set.
     """
     fot = jnp.concatenate([jnp.ones((1,), jnp.int32),
                            (tob[1:] != tob[:-1]).astype(jnp.int32)])
-    F = x.shape[1]
-    f_pad = -(-F // F_BLK) * F_BLK
-    dt = jnp.bfloat16 if bf16 else jnp.float32
-    xf = jnp.pad(x.astype(dt), ((0, 0), (0, f_pad - F)))
-    out = segment_agg_call(
-        xf, seg, tob, fot,
-        n_row_tiles=n_row_tiles, n_feat_tiles=f_pad // F_BLK,
-        op=op, interpret=interpret,
-    )
-    return out[:n_rows, :F]
+    return _reduce_blocks(x, seg, tob, fot, n_rows=n_rows,
+                          n_row_tiles=n_row_tiles, op=op,
+                          interpret=interpret, bf16=bf16)

@@ -194,7 +194,6 @@ def forward_energy_shardmap(params, positions, species, edge_src, edge_dst,
     this path is selected.
     """
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     from repro.distributed.sharding import no_constrain
 
@@ -255,11 +254,11 @@ def forward_energy_shardmap(params, positions, species, edge_src, edge_dst,
     spec_n = P(axis_names)         # node/edge arrays: dim0 sharded
     p_specs = jax.tree.map(lambda _: P(), params)   # params replicated
     with no_constrain():
-        return shard_map(
+        return jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(p_specs, spec_n, spec_n, spec_n, spec_n, spec_n, spec_n),
             out_specs=P(),
-            check_rep=False,
+            check_vma=False,
         )(params, positions, species, edge_src, edge_dst, edge_mask, node_mask)
 
 

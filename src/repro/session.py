@@ -48,6 +48,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
+import time
 import warnings
 from typing import Iterable, Mapping
 
@@ -267,16 +268,24 @@ class _EngineGroup:
         self.handles: list[int] = []
         self.window_int = int(max(1, spec.capacity or spec.size))
         self.cost = session._cost_model(agg, self.window_int)
+        # wall seconds of each build phase: basis export, push/pull
+        # decisions (min-cut unless continuous), plan compile + engine
+        self.build_seconds: dict[str, float] = {}
+        t0 = time.perf_counter()
         # sharded groups journal through per-shard DynamicOverlays inside
         # ShardedDynamic — only single-engine groups need their own fork
         basis_dyn = None if session.n_shards else session._master.fork()
         basis = (basis_dyn or session._master).to_overlay(prune=False)
+        t1 = time.perf_counter()
+        self.build_seconds["basis"] = t1 - t0
         if continuous:
             decisions = np.full(basis.n_nodes, D.PUSH, np.int64)
         else:
             wf, rf = session._frequencies(basis)
             decisions, _ = D.decide_mincut(basis, wf, rf, self.cost,
                                            window=self.window_int)
+        t0 = time.perf_counter()
+        self.build_seconds["decisions"] = t0 - t1
         if session.n_shards:
             from repro.distributed.eagr_shard import (
                 ShardedDynamic,
@@ -302,6 +311,7 @@ class _EngineGroup:
             self.engine = EagrEngine(basis, decisions, agg, spec,
                                      backend=session.backend,
                                      headroom=session.headroom)
+        self.build_seconds["plan"] = time.perf_counter() - t0
         # churn-added nodes must inherit the all-push pin, or alerted
         # readers added mid-stream would go stale (and fail alert sync)
         self.engine.pin_push = bool(continuous)

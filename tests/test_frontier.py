@@ -205,6 +205,55 @@ def test_sparse_read_bit_identical_to_dense(basis, monkeypatch):
     assert eng.plan.reader_frontier is not None
 
 
+def test_sparse_read_after_slot_patch(monkeypatch):
+    """A slot patch lands a destination's new edge in any free slot of its
+    row tile, so patched levels are no longer sorted by destination: the
+    rebuilt reader index must still cover every block holding a slot of
+    each demanded pull destination (pull readers of a min-cut group)."""
+    from repro.graphs.generators import powerlaw_graph
+
+    g = powerlaw_graph(1000, 10_000, sharing=0.5, seed=0)
+    sess = EagrSession(g)
+    h = sess.register(Query(agg="sum", window=WindowSpec("tuple", 8)))
+    rng = np.random.default_rng(1)
+    writers = np.asarray(sess.writers)
+    readers = np.asarray(sess.readers)
+    for _ in range(3):
+        ids = rng.choice(writers, 1024)
+        sess.update(ids, rng.integers(0, 8, len(ids)).astype(np.float32))
+    churned = []
+    for v in rng.permutation(readers)[:32]:
+        u = int(rng.choice(writers))
+        if u != v and u not in sess.neighborhood(int(v)):
+            sess.add_edge(u, int(v))
+            churned.append(int(v))
+    report = sess.flush()
+    assert report.patched == 1 and report.relayout == 0
+    eng = h.group.engine
+    churned = np.asarray(churned)
+    assert (eng.plan.decision[eng.plan.routes.reader_node[churned]]
+            == D.PULL).any()
+    monkeypatch.setenv("EAGR_SPARSE_WRITE", "1")
+    sparse = np.asarray(sess.read(h, churned)).reshape(-1)
+    monkeypatch.setenv("EAGR_SPARSE_WRITE", "0")
+    dense = np.asarray(sess.read(h, churned)).reshape(-1)
+    inputs = {int(r): sess.neighborhood(int(r)) for r in churned}
+    want = [np.asarray(eng.oracle_read(int(r), inputs)).item()
+            for r in churned]
+    np.testing.assert_array_equal(dense, want)
+    np.testing.assert_array_equal(sparse, want)
+
+
+def test_block_spans_cover_unsorted_slots():
+    """Per-destination spans bound every slot, in any slot order."""
+    d_s = np.array([5, 7, 5, 9, 7, 5])
+    b_s = np.array([0, 1, 1, 1, 3, 2])
+    uniq, lo, hi = F._block_spans(d_s, b_s, nb=4)
+    np.testing.assert_array_equal(uniq, [5, 7, 9])
+    np.testing.assert_array_equal(lo, [0, 1, 1])
+    np.testing.assert_array_equal(hi, [3, 4, 2])
+
+
 # -------------------------------------------------- trace/transfer discipline
 def test_sparse_k_bucketing_bounds_trace_count(basis, monkeypatch):
     """Varying batches whose frontiers land in one (batch bucket, per-level

@@ -122,3 +122,31 @@ def test_segment_agg_matches_engine_path():
     ref = np.asarray(jax.ops.segment_sum(jnp.asarray(x), jnp.asarray(seg),
                                          num_segments=n))
     np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("op", ["sum", "max"])
+@pytest.mark.parametrize("bf16", [False, True])
+def test_segment_agg_chunked_level_bit_identical(op, bf16, monkeypatch):
+    """A level reduced in several calls over block chunks (one aliased
+    accumulator, tiles split across calls resume from it) must equal the
+    single call bit for bit, down to one block per call."""
+    from repro.kernels.segment_agg import ops
+
+    n = 700
+    segs = [RNG.integers(0, n, 3000), RNG.integers(0, n // 3, 900)]
+    lp = ops.make_leveled_plan(segs, n)
+    x = jnp.asarray(RNG.normal(size=(lp.e_pad, 3)).astype(np.float32))
+
+    def run(l):
+        out = ops.segment_agg_level(
+            x, jnp.asarray(lp.seg[l]), jnp.asarray(lp.tile_of_block[l]),
+            jnp.asarray(lp.first_of_tile[l]), n_rows=n,
+            n_row_tiles=lp.n_row_tiles, op=op, bf16=bf16)
+        touched = np.zeros(n, bool)
+        touched[lp.seg[l][lp.seg[l] >= 0]] = True
+        return np.asarray(out)[touched]
+
+    whole = [run(l) for l in range(len(segs))]
+    monkeypatch.setattr(ops, "CHUNK_BYTES", 1)  # one block per kernel call
+    for l, want in enumerate(whole):
+        np.testing.assert_array_equal(run(l), want)
