@@ -7,8 +7,7 @@ per-topic benches share instead of each growing its own copy.
               over dotted metric paths — the generalized `--check`
   export      per-PR trajectory export (BENCH_trajectory.jsonl, one line per
               run, keyed by git sha) for cross-PR throughput tracking
-  watchdog    wall-clock budget guard + the optional `jax.profiler` trace
-              hook (EAGR_PROFILE_DIR)
+  watchdog    wall-clock budget guard
 """
 from benchmarks.harness.compare import check_gates, load_baselines
 from benchmarks.harness.export import export_trajectory
@@ -19,7 +18,7 @@ from benchmarks.harness.run_local import (
     percentiles,
     sustained,
 )
-from benchmarks.harness.watchdog import Watchdog, profiler_trace
+from benchmarks.harness.watchdog import Watchdog
 
 __all__ = [
     "check_gates",
@@ -31,5 +30,4 @@ __all__ = [
     "percentiles",
     "sustained",
     "Watchdog",
-    "profiler_trace",
 ]

@@ -1,9 +1,7 @@
-"""Run guards: a wall-clock budget watchdog (so a hung sustained loop fails
-loudly with stacks instead of eating the CI job timeout) and the optional
-``jax.profiler`` trace hook gated on ``EAGR_PROFILE_DIR``."""
+"""Run guard: a wall-clock budget watchdog, so a hung sustained loop fails
+loudly with stacks instead of eating the CI job timeout."""
 from __future__ import annotations
 
-import contextlib
 import faulthandler
 import os
 import sys
@@ -41,19 +39,3 @@ class Watchdog:
             self._timer.cancel()
             self._timer = None
 
-
-@contextlib.contextmanager
-def profiler_trace(name: str = "trace"):
-    """Wrap a region in ``jax.profiler.trace`` when ``EAGR_PROFILE_DIR`` is
-    set; otherwise a no-op. The trace lands in
-    ``$EAGR_PROFILE_DIR/<name>`` for TensorBoard / Perfetto."""
-    out = os.environ.get("EAGR_PROFILE_DIR")
-    if not out:
-        yield
-        return
-    import jax
-
-    target = os.path.join(out, name)
-    os.makedirs(target, exist_ok=True)
-    with jax.profiler.trace(target):
-        yield

@@ -44,7 +44,6 @@ from benchmarks.harness import (
     export_trajectory,
     load_baselines,
     percentiles,
-    profiler_trace,
     sustained,
 )
 from repro.core import dataflow as D
@@ -335,7 +334,7 @@ def run_streaming_bench(quick: bool = False, check: bool = False,
                 lambda ids, vals: (eng.write_batch(
                     ids, vals, batch_size=cfg["arrival"]), len(ids))[1],
                 duration_s=cfg["duration_s"], barrier=barrier, warmup=2)
-        with phases.phase("pipeline"), profiler_trace("streaming_pipeline"):
+        with phases.phase("pipeline"):
             pipe = IngestPipeline([eng], depth=2,
                                   device_batch=cfg["device_batch"])
             res = _run_mode(

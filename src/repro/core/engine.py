@@ -475,15 +475,17 @@ def _level_reduce(meta: PlanMeta, tables: LevelTables, l, val: jnp.ndarray,
     ``val`` (n_nodes, F). ``l`` may be traced (fori_loop) or a Python int
     (xla_unrolled). Rows outside the level's touched set are undefined —
     callers mask. op: 'sum' (signed) | 'max' | 'min'."""
-    seg, src, sign = tables.seg[l], tables.src[l], tables.sign[l]
-    x = val[src]
-    if op == "sum":
-        x = x * sign[:, None]
+    with jax.named_scope("eagr.level_gather"):
+        seg, src, sign = tables.seg[l], tables.src[l], tables.sign[l]
+        tob, fot = tables.tile_of_block[l], tables.first_of_tile[l]
+        x = val[src]
+        if op == "sum":
+            x = x * sign[:, None]
     if meta.backend == "pallas":
         kern_op = "max" if op in ("max", "min") else "sum"
         xk = -x if op == "min" else x
         out = segment_agg_level(
-            xk, seg, tables.tile_of_block[l], tables.first_of_tile[l],
+            xk, seg, tob, fot,
             n_rows=meta.n_nodes, n_row_tiles=meta.n_row_tiles,
             op=kern_op, interpret=meta.interpret, bf16=meta.bf16)
         return -out if op == "min" else out
@@ -508,12 +510,13 @@ def _gather_active(meta: PlanMeta, tables: LevelTables, l, active_l):
     padding lanes gather a real block but are neutralized to the slot-padding
     pattern (seg -1, src 0, sign 0) every backend already drops."""
     nb = tables.tile_of_block.shape[1]
-    ab = jnp.minimum(active_l, nb - 1)
-    valid = (active_l < nb)[:, None]
-    seg_c = jnp.where(valid, tables.seg[l].reshape(nb, E_BLK)[ab], -1)
-    src_c = jnp.where(valid, tables.src[l].reshape(nb, E_BLK)[ab], 0)
-    sign_c = jnp.where(valid, tables.sign[l].reshape(nb, E_BLK)[ab], 0.0)
-    tob_c = tables.tile_of_block[l][ab]
+    with jax.named_scope("eagr.level_gather"):
+        ab = jnp.minimum(active_l, nb - 1)
+        valid = (active_l < nb)[:, None]
+        seg_c = jnp.where(valid, tables.seg[l].reshape(nb, E_BLK)[ab], -1)
+        src_c = jnp.where(valid, tables.src[l].reshape(nb, E_BLK)[ab], 0)
+        sign_c = jnp.where(valid, tables.sign[l].reshape(nb, E_BLK)[ab], 0.0)
+        tob_c = tables.tile_of_block[l][ab]
     return (seg_c.reshape(-1), src_c.reshape(-1), sign_c.reshape(-1), tob_c)
 
 
@@ -522,9 +525,10 @@ def _level_reduce_active(meta: PlanMeta, seg_c, src_c, sign_c, tob_c,
     """``_level_reduce`` over a compacted active-block edge subset: the
     gather, the kernel grid, and the segment reduction all shrink from the
     level's padded edge capacity to K*E_BLK."""
-    x = val[src_c]
-    if op == "sum":
-        x = x * sign_c[:, None]
+    with jax.named_scope("eagr.level_gather"):
+        x = val[src_c]
+        if op == "sum":
+            x = x * sign_c[:, None]
     if meta.backend == "pallas":
         kern_op = "max" if op in ("max", "min") else "sum"
         xk = -x if op == "min" else x
@@ -574,24 +578,64 @@ def _level_loop(meta: PlanMeta, body, init):
 # embed them in larger programs — ``distributed/stacked.py`` vmaps/shard_maps
 # them over a leading shard axis — while the jitted single-engine wrappers
 # below keep their own cache entries.
+def _sum_writes(meta: PlanMeta, agg: Aggregate, spec: WindowSpec,
+                arrays: PlanArrays, state: EngineState, rows, vals, mask):
+    """Head of the sum write bodies: the window update, and each written
+    writer's PAO delta (new entries less evicted ones) scattered onto its
+    overlay node. Returns ``(windows, delta)``."""
+    with jax.named_scope("eagr.apply_writes"):
+        windows, evicted, evicted_valid = apply_writes(
+            state.windows, spec, rows, vals,
+            jnp.full(rows.shape, state.now, jnp.float32), mask)
+        delta_w = agg.lift(vals) * mask[:, None].astype(jnp.float32)
+        delta_w -= agg.lift(evicted) \
+            * evicted_valid[:, None].astype(jnp.float32)
+        delta = jnp.zeros((meta.n_nodes, agg.pao_dim), dtype=jnp.float32)
+        return windows, delta.at[arrays.writer_node[rows]].add(
+            delta_w, mode="drop")
+
+
+def _extremal_writes(meta: PlanMeta, agg: Aggregate, spec: WindowSpec,
+                     arrays: PlanArrays, state: EngineState, rows, vals, mask,
+                     prev_now):
+    """Head of the extremal write bodies: the window update, the touched
+    writers' recomputed PAOs written onto their overlay nodes, and the
+    changed-node mask the level sweep propagates. Returns ``(windows, pao,
+    changed)``."""
+    with jax.named_scope("eagr.apply_writes"):
+        windows, _, _ = apply_writes(
+            state.windows, spec, rows, vals,
+            jnp.full(rows.shape, state.now, jnp.float32), mask)
+        wp = window_pao(windows, spec, agg, now=state.now)
+        touched_w = jnp.zeros((meta.n_writers,), bool).at[rows].max(
+            mask, mode="drop")
+        if spec.kind == "time":  # tuple windows only evict on write
+            touched_w = touched_w | stale_rows(state.windows, spec, prev_now,
+                                               state.now)
+        old_w = state.pao[jnp.minimum(arrays.writer_node, meta.n_nodes - 1)]
+        new_w = jnp.where(touched_w[:, None], wp, old_w)
+        pao = state.pao.at[arrays.writer_node].set(new_w, mode="drop")
+        changed = jnp.zeros((meta.n_nodes + 1,), bool).at[
+            arrays.writer_node].max(touched_w, mode="promise_in_bounds")
+        return windows, pao, changed
+
+
 def write_step_sum(meta: PlanMeta, agg: Aggregate, spec: WindowSpec,
                    arrays: PlanArrays, state: EngineState, rows, vals, mask):
-    windows, evicted, evicted_valid = apply_writes(
-        state.windows, spec, rows, vals,
-        jnp.full(rows.shape, state.now, jnp.float32), mask)
-    delta_w = agg.lift(vals) * mask[:, None].astype(jnp.float32)
-    delta_w -= agg.lift(evicted) * evicted_valid[:, None].astype(jnp.float32)
-    delta = jnp.zeros((meta.n_nodes, agg.pao_dim), dtype=jnp.float32)
-    delta = delta.at[arrays.writer_node[rows]].add(delta_w, mode="drop")
+    windows, delta = _sum_writes(meta, agg, spec, arrays, state, rows,
+                                 vals, mask)
 
     def level(l, delta):
         contrib = _level_reduce(meta, arrays.push, l, delta, "sum")
         # untouched rows are undefined kernel output (uninitialized tiles) —
         # only the level's destinations may accumulate
-        return delta + jnp.where(arrays.push.touched[l][:, None], contrib, 0.0)
+        with jax.named_scope("eagr.level_combine"):
+            return delta + jnp.where(arrays.push.touched[l][:, None],
+                                     contrib, 0.0)
 
     delta = _level_loop(meta, level, delta)
-    pao = state.pao + delta
+    with jax.named_scope("eagr.level_combine"):
+        pao = state.pao + delta
     return EngineState(windows, pao, state.now + 1.0)
 
 
@@ -605,33 +649,22 @@ def write_step_extremal(meta: PlanMeta, agg: Aggregate, spec: WindowSpec,
     the level sweep only overwrites destinations downstream of a touched
     writer, so the recompute is confined to the changed closure instead of
     every writer and every push node per batch."""
-    windows, _, _ = apply_writes(
-        state.windows, spec, rows, vals,
-        jnp.full(rows.shape, state.now, jnp.float32), mask)
-    wp = window_pao(windows, spec, agg, now=state.now)
-    written = jnp.zeros((meta.n_writers,), bool).at[rows].max(mask, mode="drop")
-    if spec.kind == "time":
-        touched_w = written | stale_rows(state.windows, spec, prev_now, state.now)
-    else:
-        touched_w = written  # tuple windows only evict on write
-    old_w = state.pao[jnp.minimum(arrays.writer_node, meta.n_nodes - 1)]
-    new_w = jnp.where(touched_w[:, None], wp, old_w)
-    pao = state.pao.at[arrays.writer_node].set(new_w, mode="drop")
-    changed = jnp.zeros((meta.n_nodes + 1,), bool)
-    changed = changed.at[arrays.writer_node].max(touched_w, mode="promise_in_bounds")
+    windows, pao, changed = _extremal_writes(
+        meta, agg, spec, arrays, state, rows, vals, mask, prev_now)
 
     def level(l, carry):
         pao, changed = carry
         new = _level_reduce(meta, arrays.push, l, pao, agg.combine)
-        seg = arrays.push.seg[l]
-        dst = jnp.where(seg >= 0, seg, meta.n_nodes)
-        ch = jax.ops.segment_max(
-            changed[arrays.push.src[l]].astype(jnp.int32), dst,
-            num_segments=meta.n_nodes + 1) > 0
-        upd = arrays.push.touched[l] & ch[: meta.n_nodes]
-        pao = jnp.where(upd[:, None], new, pao)
-        changed = changed.at[: meta.n_nodes].max(upd)
-        return pao, changed
+        with jax.named_scope("eagr.level_combine"):
+            seg = arrays.push.seg[l]
+            dst = jnp.where(seg >= 0, seg, meta.n_nodes)
+            ch = jax.ops.segment_max(
+                changed[arrays.push.src[l]].astype(jnp.int32), dst,
+                num_segments=meta.n_nodes + 1) > 0
+            upd = arrays.push.touched[l] & ch[: meta.n_nodes]
+            pao = jnp.where(upd[:, None], new, pao)
+            changed = changed.at[: meta.n_nodes].max(upd)
+            return pao, changed
 
     pao, _ = _level_loop(meta, level, (pao, changed))
     return EngineState(windows, pao, state.now + 1.0)
@@ -650,11 +683,13 @@ def refresh_pao_step(meta: PlanMeta, agg: Aggregate, spec: WindowSpec,
 
     def level(l, pao):
         new = _level_reduce(meta, arrays.push, l, pao, agg.combine)
-        return jnp.where(arrays.push.touched[l][:, None], new, pao)
+        with jax.named_scope("eagr.level_combine"):
+            return jnp.where(arrays.push.touched[l][:, None], new, pao)
 
     return _level_loop(meta, level, pao)
 
 
+@jax.named_scope("eagr.read_step")
 def read_step(meta: PlanMeta, agg: Aggregate, arrays: PlanArrays,
               state: EngineState, reader_nodes, mask):
     decision = arrays.decision
@@ -674,7 +709,9 @@ def read_step(meta: PlanMeta, agg: Aggregate, arrays: PlanArrays,
     def level(l, val):  # level ascending
         computed = _level_reduce(meta, arrays.pull, l, val, agg.combine)
         # only overwrite rows that this level actually computed
-        return jnp.where(take & arrays.pull.touched[l][:, None], computed, val)
+        with jax.named_scope("eagr.level_combine"):
+            return jnp.where(take & arrays.pull.touched[l][:, None],
+                             computed, val)
 
     val = _level_loop(meta, level, val)
     answers = val[reader_nodes]
@@ -690,13 +727,8 @@ def read_step(meta: PlanMeta, agg: Aggregate, arrays: PlanArrays,
 def write_step_sum_sparse(meta: PlanMeta, agg: Aggregate, spec: WindowSpec,
                           arrays: PlanArrays, state: EngineState, rows, vals,
                           mask, active):
-    windows, evicted, evicted_valid = apply_writes(
-        state.windows, spec, rows, vals,
-        jnp.full(rows.shape, state.now, jnp.float32), mask)
-    delta_w = agg.lift(vals) * mask[:, None].astype(jnp.float32)
-    delta_w -= agg.lift(evicted) * evicted_valid[:, None].astype(jnp.float32)
-    delta = jnp.zeros((meta.n_nodes, agg.pao_dim), dtype=jnp.float32)
-    delta = delta.at[arrays.writer_node[rows]].add(delta_w, mode="drop")
+    windows, delta = _sum_writes(meta, agg, spec, arrays, state, rows,
+                                 vals, mask)
 
     # Python unroll, not fori_loop: the active tuple is ragged (one bucketed
     # width per level), and levels whose frontier is empty cost nothing
@@ -707,9 +739,11 @@ def write_step_sum_sparse(meta: PlanMeta, agg: Aggregate, spec: WindowSpec,
             meta, arrays.push, l, active[l])
         contrib = _level_reduce_active(
             meta, seg_c, src_c, sign_c, tob_c, delta, "sum")
-        ra = arrays.push.touched[l] & _row_active(meta, seg_c)
-        delta = delta + jnp.where(ra[:, None], contrib, 0.0)
-    pao = state.pao + delta
+        with jax.named_scope("eagr.level_combine"):
+            ra = arrays.push.touched[l] & _row_active(meta, seg_c)
+            delta = delta + jnp.where(ra[:, None], contrib, 0.0)
+    with jax.named_scope("eagr.level_combine"):
+        pao = state.pao + delta
     return EngineState(windows, pao, state.now + 1.0)
 
 
@@ -717,20 +751,8 @@ def write_step_extremal_sparse(meta: PlanMeta, agg: Aggregate,
                                spec: WindowSpec, arrays: PlanArrays,
                                state: EngineState, rows, vals, mask,
                                prev_now, active):
-    windows, _, _ = apply_writes(
-        state.windows, spec, rows, vals,
-        jnp.full(rows.shape, state.now, jnp.float32), mask)
-    wp = window_pao(windows, spec, agg, now=state.now)
-    written = jnp.zeros((meta.n_writers,), bool).at[rows].max(mask, mode="drop")
-    if spec.kind == "time":
-        touched_w = written | stale_rows(state.windows, spec, prev_now, state.now)
-    else:
-        touched_w = written
-    old_w = state.pao[jnp.minimum(arrays.writer_node, meta.n_nodes - 1)]
-    new_w = jnp.where(touched_w[:, None], wp, old_w)
-    pao = state.pao.at[arrays.writer_node].set(new_w, mode="drop")
-    changed = jnp.zeros((meta.n_nodes + 1,), bool)
-    changed = changed.at[arrays.writer_node].max(touched_w, mode="promise_in_bounds")
+    windows, pao, changed = _extremal_writes(
+        meta, agg, spec, arrays, state, rows, vals, mask, prev_now)
 
     for l in range(meta.n_levels):  # ragged active tuple: Python unroll
         if active[l].shape[0] == 0:
@@ -739,19 +761,21 @@ def write_step_extremal_sparse(meta: PlanMeta, agg: Aggregate,
             meta, arrays.push, l, active[l])
         new = _level_reduce_active(
             meta, seg_c, src_c, sign_c, tob_c, pao, agg.combine)
-        dst = jnp.where(seg_c >= 0, seg_c, meta.n_nodes)
-        ch = jax.ops.segment_max(
-            changed[src_c].astype(jnp.int32), dst,
-            num_segments=meta.n_nodes + 1) > 0
-        upd = arrays.push.touched[l] & ch[: meta.n_nodes]
-        pao = jnp.where(upd[:, None], new, pao)
-        changed = changed.at[: meta.n_nodes].max(upd)
+        with jax.named_scope("eagr.level_combine"):
+            dst = jnp.where(seg_c >= 0, seg_c, meta.n_nodes)
+            ch = jax.ops.segment_max(
+                changed[src_c].astype(jnp.int32), dst,
+                num_segments=meta.n_nodes + 1) > 0
+            upd = arrays.push.touched[l] & ch[: meta.n_nodes]
+            pao = jnp.where(upd[:, None], new, pao)
+            changed = changed.at[: meta.n_nodes].max(upd)
     return EngineState(windows, pao, state.now + 1.0)
 
 
 DEM_CHUNK = 256  # demand slots per active chunk (d_pad is a multiple of 256)
 
 
+@jax.named_scope("eagr.read_step")
 def read_step_sparse(meta: PlanMeta, agg: Aggregate, arrays: PlanArrays,
                      state: EngineState, reader_nodes, mask, dem_active,
                      pull_active):
@@ -792,8 +816,9 @@ def read_step_sparse(meta: PlanMeta, agg: Aggregate, arrays: PlanArrays,
             meta, arrays.pull, l, pull_active[l])
         computed = _level_reduce_active(
             meta, seg_c, src_c, sign_c, tob_c, val, agg.combine)
-        ra = arrays.pull.touched[l] & _row_active(meta, seg_c)
-        val = jnp.where(take & ra[:, None], computed, val)
+        with jax.named_scope("eagr.level_combine"):
+            ra = arrays.pull.touched[l] & _row_active(meta, seg_c)
+            val = jnp.where(take & ra[:, None], computed, val)
     answers = val[reader_nodes]
     return agg.finalize(answers), answers
 
@@ -1146,6 +1171,14 @@ class EagrEngine:
             -1 if active is None else sum(a.shape[0] for a in active))
         if len(self.frontier_log) > (1 << 20):
             del self.frontier_log[: (1 << 19)]
+        with jax.profiler.TraceAnnotation("eagr.put",
+                                          batch=int(self._now_host)):
+            self._put_step(rows, vals, mask, n_live, active)
+        self._now_host += 1.0
+
+    def _put_step(self, rows, vals, mask, n_live: int, active) -> None:
+        """One explicit ``device_put`` of the routed batch (and its active
+        blocks), then the async jitted write step."""
         rows_d, vals_d, mask_d = jax.device_put(
             (np.ascontiguousarray(rows, np.int32),
              np.ascontiguousarray(vals, np.float32),
@@ -1183,7 +1216,6 @@ class EagrEngine:
         else:
             self.state = self._write_sparse(self.state, rows_d, vals_d,
                                             mask_d, *extra)
-        self._now_host += 1.0
 
     # -------------------------------------------------- structural updates
     def apply_delta(self, delta, *, growth: float = 2.0):
@@ -1265,29 +1297,35 @@ class EagrEngine:
         lookup; the batch pads to the ``bucket_batch`` bucket unless
         ``batch_size`` pins the shape."""
         base_ids = np.asarray(base_ids)
-        nodes, known = self.plan.routes.reader_nodes(base_ids)
-        if not known.all():
-            bad = np.asarray(base_ids, np.int64).reshape(-1)[~known]
-            raise ValueError(
-                f"read_batch: base ids {sorted(set(map(int, bad)))[:8]} are "
-                f"not readers of this overlay (no reader node registered)")
-        B = batch_size or bucket_batch(len(nodes))
-        if B < len(nodes):
-            raise ValueError(f"batch_size={B} < batch of {len(nodes)}")
-        act = self._reader_active(nodes)
-        pad = B - len(nodes)
-        mask = np.concatenate([np.ones(len(nodes), bool), np.zeros(pad, bool)])
-        nodes = np.concatenate([nodes, np.zeros(pad, np.int32)])
-        nodes_d, mask_d = jax.device_put((nodes, mask))
-        if act is None:
-            ans, _ = self._read(self.state, nodes_d, mask_d)
-        else:
-            dem_d, pull_d = jax.device_put(
-                (tuple(np.ascontiguousarray(a, np.int32) for a in act[0]),
-                 tuple(np.ascontiguousarray(a, np.int32) for a in act[1])))
-            ans, _ = self._read_sparse(self.state, nodes_d, mask_d,
-                                       dem_d, pull_d)
-        return np.asarray(jax.device_get(ans))[: len(base_ids)]
+        with jax.profiler.TraceAnnotation("eagr.read_route"):
+            nodes, known = self.plan.routes.reader_nodes(base_ids)
+            if not known.all():
+                bad = np.asarray(base_ids, np.int64).reshape(-1)[~known]
+                raise ValueError(
+                    f"read_batch: base ids {sorted(set(map(int, bad)))[:8]} "
+                    f"are not readers of this overlay (no reader node "
+                    f"registered)")
+            B = batch_size or bucket_batch(len(nodes))
+            if B < len(nodes):
+                raise ValueError(f"batch_size={B} < batch of {len(nodes)}")
+            act = self._reader_active(nodes)
+        with jax.profiler.TraceAnnotation("eagr.put"):
+            pad = B - len(nodes)
+            mask = np.concatenate([np.ones(len(nodes), bool),
+                                   np.zeros(pad, bool)])
+            nodes = np.concatenate([nodes, np.zeros(pad, np.int32)])
+            nodes_d, mask_d = jax.device_put((nodes, mask))
+            if act is None:
+                ans, _ = self._read(self.state, nodes_d, mask_d)
+            else:
+                dem_d, pull_d = jax.device_put(
+                    (tuple(np.ascontiguousarray(a, np.int32) for a in act[0]),
+                     tuple(np.ascontiguousarray(a, np.int32)
+                           for a in act[1])))
+                ans, _ = self._read_sparse(self.state, nodes_d, mask_d,
+                                           dem_d, pull_d)
+        with jax.profiler.TraceAnnotation("eagr.read_wait"):
+            return np.asarray(jax.device_get(ans))[: len(base_ids)]
 
     def _reader_active(self, nodes: np.ndarray):
         """Read-path twin of :meth:`frontier_active`: ``(dem_active,

@@ -345,11 +345,16 @@ def _reduce_blocks(x, seg, tob, fot, *, n_rows: int, n_row_tiles: int,
 
     def chunk(i, acc):
         take = lambda a, n: jax.lax.dynamic_slice_in_dim(a, i * n, n)
-        xc = jnp.pad(take(x, c * E_BLK).astype(dt), ((0, 0), (0, f_pad - F)))
-        return segment_agg_call(xc, take(seg, c * E_BLK), take(tob, c),
-                                take(fot, c), acc, op=op, interpret=interpret)
+        with jax.named_scope("eagr.lane_pad"):
+            xc = jnp.pad(take(x, c * E_BLK).astype(dt),
+                         ((0, 0), (0, f_pad - F)))
+        with jax.named_scope("eagr.segment_agg"):
+            return segment_agg_call(xc, take(seg, c * E_BLK), take(tob, c),
+                                    take(fot, c), acc, op=op,
+                                    interpret=interpret)
 
-    acc = jnp.zeros((n_row_tiles * R_BLK, f_pad), jnp.float32)
+    with jax.named_scope("eagr.lane_pad"):
+        acc = jnp.zeros((n_row_tiles * R_BLK, f_pad), jnp.float32)
     if n_blocks == c:
         acc = chunk(0, acc)
     else:
@@ -357,7 +362,8 @@ def _reduce_blocks(x, seg, tob, fot, *, n_rows: int, n_row_tiles: int,
     # through the transpose: a plain [:n_rows, :F] slice keeps the
     # accumulator's (8, 128) tiling, and on TPU every (n_rows, F) array fed
     # by it (the level-loop carry) then pads F to 128 lanes in HBM
-    return acc.T[:F, :n_rows].T
+    with jax.named_scope("eagr.lane_pad"):
+        return acc.T[:F, :n_rows].T
 
 
 def segment_agg_level(x: jnp.ndarray, seg: jnp.ndarray, tob: jnp.ndarray,

@@ -127,5 +127,6 @@ def segment_agg_call(
         out_shape=jax.ShapeDtypeStruct(acc.shape, jnp.float32),
         input_output_aliases={4: 0},
         interpret=interpret,
+        name=f"segment_agg_call_{op}",
     )(tile_of_block, first_of_tile, seg.reshape(n_edge_blocks, 1, E_BLK), x,
       acc)

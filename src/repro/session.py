@@ -47,11 +47,12 @@ suite (``tests/test_session.py``) holds the session bit-identical to it.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
 import time
-import warnings
 from typing import Iterable, Mapping
 
+import jax
 import numpy as np
 
 from repro.core import dataflow as D
@@ -64,6 +65,12 @@ from repro.core.window import WindowSpec
 
 __all__ = ["Query", "QueryHandle", "EagrSession", "bucket_batch",
            "SessionStats", "FlushReport", "AdaptReport", "AlertHandle"]
+
+
+def _span(name: str):
+    """Decorator: each call is one host span ``name`` in a profiler trace
+    (``jax.profiler``; a no-op check while no trace is being taken)."""
+    return functools.partial(jax.profiler.annotate_function, name=name)
 
 
 # ------------------------------------------------------------------- queries
@@ -248,6 +255,8 @@ class SessionStats:
     patches_applied: int            # in-place device patches, all plans
     frontier: dict                  # frontier-size distribution (write path)
     ingest: "object | None"         # streams.ingest.IngestStats
+    alerts: "object | None"         # streams.alerts.AlertStats, summed over
+                                    # groups; None without standing alerts
     construction: "object | None"   # core.vnm.ConstructionStats
     last_checkpoint_step: "int | None"
 
@@ -646,6 +655,7 @@ class EagrSession:
     def alerts(self) -> list[AlertHandle]:
         return list(self._alerts.values())
 
+    @_span("eagr.drain")
     def drain_fired(self, ahandle: AlertHandle | None = None) -> list:
         """Collect every fired batch produced since the last drain — the
         compact readback of all standing alerts (optionally filtered to one
@@ -696,6 +706,7 @@ class EagrSession:
         return set(self._master.reader_inputs.get(int(reader), set()))
 
     # -------------------------------------------------------------- execution
+    @_span("eagr.update")
     def update(self, src_ids, values=None) -> None:
         """Apply one batch of writes (base writer ids + raw values) to every
         registered query — the session's single shared write stream. Values
@@ -732,6 +743,7 @@ class EagrSession:
                 and self._seq % self.ckpt_every == 0:
             self.save(blocking=False)
 
+    @_span("eagr.read")
     def read(self, handle: QueryHandle, ids) -> np.ndarray:
         """Answer one batch of ego-centric reads for a registered query.
         Raises for ids outside the query's ``readers`` scope or unknown to
@@ -746,25 +758,31 @@ class EagrSession:
             self._pipeline.drain()
         ids = np.asarray(ids, np.int64).reshape(-1)
         if handle.readers is not None:
-            arr = handle._reader_arr
-            if arr is None:
-                arr = np.fromiter(handle.readers, np.int64,
-                                  len(handle.readers))
-                arr.sort()
-                object.__setattr__(handle, "_reader_arr", arr)
-            inside = np.isin(ids, arr)
-            if not inside.all():
-                raise ValueError(
-                    f"read: base ids "
-                    f"{sorted(set(map(int, ids[~inside])))[:8]} are outside "
-                    f"this query's readers scope")
+            with jax.profiler.TraceAnnotation("eagr.read_route"):
+                self._check_scope(handle, ids)
         out = handle.group.engine.read_batch(ids,
                                              batch_size=bucket_batch(len(ids)))
-        if len(ids):
-            self._grow_counts(int(ids.max()))
-            np.add.at(self._rcount, ids, 1.0)
-        self._tick()
+        with jax.profiler.TraceAnnotation("eagr.read_bookkeeping"):
+            if len(ids):
+                self._grow_counts(int(ids.max()))
+                np.add.at(self._rcount, ids, 1.0)
+            self._tick()
         return out
+
+    @staticmethod
+    def _check_scope(handle: QueryHandle, ids: np.ndarray) -> None:
+        """Raise for ids outside the query's ``readers`` scope."""
+        arr = handle._reader_arr
+        if arr is None:
+            arr = np.fromiter(handle.readers, np.int64, len(handle.readers))
+            arr.sort()
+            object.__setattr__(handle, "_reader_arr", arr)
+        inside = np.isin(ids, arr)
+        if not inside.all():
+            raise ValueError(
+                f"read: base ids "
+                f"{sorted(set(map(int, ids[~inside])))[:8]} are outside "
+                f"this query's readers scope")
 
     # --------------------------------------------------------------- mutations
     def add_edge(self, u: int, v: int, *, affected=None) -> None:
@@ -802,6 +820,7 @@ class EagrSession:
         for group in self._groups.values():
             group._journal.delete_node(u)
 
+    @_span("eagr.flush")
     def flush(self) -> FlushReport:
         """Drain every group's mutation journal into its live plan through
         the §3.3 patch path (device-resident ``PatchProgram``; recompile only
@@ -853,8 +872,8 @@ class EagrSession:
     def stats(self) -> SessionStats:
         """One consolidated :class:`SessionStats` snapshot: ingest,
         construction, frontier and patch counters plus the checkpoint
-        position. Supersedes reaching for ``ingest_stats`` /
-        ``overlay_stats`` / hand-rolled frontier summaries."""
+        position. Supersedes reaching for ``overlay_stats`` /
+        hand-rolled frontier summaries."""
         from repro.core.frontier import frontier_summary
 
         logs: list[int] = []
@@ -877,6 +896,7 @@ class EagrSession:
             patches_applied=patches,
             frontier=frontier_summary(logs),
             ingest=self._ingest_stats(),
+            alerts=self._alert_stats(),
             construction=self.overlay_stats,
             last_checkpoint_step=self._last_ckpt_step,
         )
@@ -886,15 +906,16 @@ class EagrSession:
             return self._pipeline.stats
         return self._carry_ingest
 
-    @property
-    def ingest_stats(self):
-        """Deprecated alias for ``stats().ingest`` — the live
-        :class:`repro.streams.ingest.IngestStats` (``None`` until the first
-        pipelined update; survives checkpoint/restore)."""
-        warnings.warn(
-            "EagrSession.ingest_stats is deprecated; use stats().ingest",
-            DeprecationWarning, stacklevel=2)
-        return self._ingest_stats()
+    def _alert_stats(self):
+        """The groups' ``AlertStats`` summed; ``None`` without an alert."""
+        from repro.streams.alerts import AlertStats
+
+        sets = [a for a in (getattr(g.engine, "alerts", None)
+                            for g in self._groups.values()) if a is not None]
+        if not sets:
+            return None
+        return AlertStats(*(sum(getattr(a.stats, f.name) for a in sets)
+                            for f in dataclasses.fields(AlertStats)))
 
     # ------------------------------------------------------------- durability
     def save(self, directory: str | None = None, *, step: int | None = None,

@@ -1,4 +1,10 @@
-from repro.streams.alerts import AlertSet, AlertSpec, FiredBatch, PollOracle
+from repro.streams.alerts import (
+    AlertSet,
+    AlertSpec,
+    AlertStats,
+    FiredBatch,
+    PollOracle,
+)
 from repro.streams.ingest import IngestPipeline, IngestStats
 from repro.streams.traces import (
     Trace,
@@ -18,6 +24,7 @@ __all__ = [
     "IngestStats",
     "AlertSpec",
     "AlertSet",
+    "AlertStats",
     "FiredBatch",
     "PollOracle",
 ]

@@ -166,6 +166,15 @@ class FiredBatch:
         return len(self.base_ids)
 
 
+@dataclasses.dataclass
+class AlertStats:
+    """Fired-set readback counters of one :class:`AlertSet`."""
+
+    batches_collected: int = 0  # device batches whose fired set was read back
+    overflow_batches: int = 0   # of those, batches whose compact buffer was
+                                # full, so the dense fired/m vectors were read
+
+
 # ------------------------------------------------------------- device bodies
 def _measure(agg: Aggregate, pao: jnp.ndarray, comp: jnp.ndarray
              ) -> jnp.ndarray:
@@ -216,8 +225,9 @@ def alert_write_step(step, meta, agg: Aggregate, spec, cap: int, arrays,
     evaluation reads the post-step PAO at the step's own eval instant
     (``new_now - 1``: the step increments the clock on return)."""
     ns = step(meta, agg, spec, arrays, state, rows, vals, mask, *extra)
-    new_a, count, idx, avals, fired, m = alert_eval(
-        agg, astate, ns.pao, ns.now - 1.0, cap)
+    with jax.named_scope("eagr.alert_eval"):
+        new_a, count, idx, avals, fired, m = alert_eval(
+            agg, astate, ns.pao, ns.now - 1.0, cap)
     return ns, new_a, count, idx, avals, fired, m
 
 
@@ -291,6 +301,7 @@ class AlertSet:
         # the slot's token barrier proves those steps completed
         self.seq = 0        # fused steps dispatched (push_pending calls)
         self.seq_done = 0   # pending entries read back (collect pops)
+        self.stats = AlertStats()
 
     # ------------------------------------------------------------ properties
     @property
@@ -525,14 +536,19 @@ class AlertSet:
         for _ in range(n):
             now, count, idx, vals, fired, m = self._pending.popleft()
             self.seq_done += 1
-            cd = np.asarray(jax.device_get(count))
-            # stacked: the psum'd global total, replicated over the shard
-            # axis — one scalar readback regardless of shard count
-            total = int(cd.reshape(-1)[0]) if cd.ndim else int(cd)
-            if total == 0:
-                continue
-            batch = self._to_batch(now, idx, vals, fired, m)
-            if len(batch):
+            self.stats.batches_collected += 1
+            dense0 = self.stats.overflow_batches
+            with jax.profiler.TraceAnnotation(
+                    "eagr.fired_readback", batch=int(now)) as span:
+                cd = np.asarray(jax.device_get(count))
+                # stacked: the psum'd global total, replicated over the
+                # shard axis — one scalar readback regardless of shard count
+                total = int(cd.reshape(-1)[0]) if cd.ndim else int(cd)
+                batch = self._to_batch(now, idx, vals, fired, m) \
+                    if total else None
+                span.set_metadata(
+                    overflow=self.stats.overflow_batches - dense0)
+            if batch is not None and len(batch):
                 self.fired.append(batch)
                 out += 1
         return out
@@ -553,6 +569,7 @@ class AlertSet:
             # exact set from the dense fired vector (rare path, one transfer)
             if k == self.cap:
                 if fired_h is None:
+                    self.stats.overflow_batches += 1
                     fired_h = np.asarray(jax.device_get(fired))
                     m_h = np.asarray(jax.device_get(m))
                     if not self._stacked:

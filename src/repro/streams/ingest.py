@@ -146,6 +146,25 @@ class IngestPipeline:
 
     # ---------------------------------------------------------------- dispatch
     def _dispatch(self, n: int) -> None:
+        with jax.profiler.TraceAnnotation(
+                "eagr.dispatch", batch=int(self.engines[0]._now_host)):
+            self._send(n)
+        # advance the ring; the next slot's buffers may still back an
+        # in-flight step — the pipeline's only steady-state sync point
+        self._slot = (self._slot + 1) % self.depth
+        self._fill = 0
+        tok = self._tokens[self._slot]
+        if tok is not None:
+            t0 = time.perf_counter()
+            with jax.profiler.TraceAnnotation("eagr.ring_wait"):
+                jax.block_until_ready(tok)
+            self.stats.stall_s += time.perf_counter() - t0
+            self._tokens[self._slot] = None
+            self._collect_marks(self._slot)
+
+    def _send(self, n: int) -> None:
+        """Route the current slot's first ``n`` events and hand them to
+        every engine; record the slot's completion token."""
         s, B = self._slot, self.device_batch
         ids, vals = self._ids[s], self._vals[s]
         if n < B:
@@ -161,19 +180,22 @@ class IngestPipeline:
                 eng.write_batch(ids[:n], vals[:n], batch_size=B)
                 dropped = 0
                 continue
-            rows, mask = routes.writer_rows(ids)
-            n_live = int(np.count_nonzero(mask))
-            dropped = min(dropped, n - n_live)
-            v = vals
-            if n_live < n:
-                # zero dead lanes: their values are dead under the mask, but
-                # keep non-finite garbage out of the masked multiply
-                v = np.where(mask.reshape((-1,) + (1,) * (vals.ndim - 1)),
-                             vals, 0.0)
+            with jax.profiler.TraceAnnotation("eagr.route"):
+                rows, mask = routes.writer_rows(ids)
+                n_live = int(np.count_nonzero(mask))
+                dropped = min(dropped, n - n_live)
+                v = vals
+                if n_live < n:
+                    # zero dead lanes: their values are dead under the mask,
+                    # but keep non-finite garbage out of the masked multiply
+                    v = np.where(
+                        mask.reshape((-1,) + (1,) * (vals.ndim - 1)),
+                        vals, 0.0)
             # expand this coalesced device batch's frontier here (host side,
             # before dispatch) so the sparse-vs-dense decision and the
             # active-block bucket are pinned per batch, not per event
-            act = eng.frontier_active(rows, mask, n_live=n_live)
+            with jax.profiler.TraceAnnotation("eagr.expand"):
+                act = eng.frontier_active(rows, mask, n_live=n_live)
             eng.write_rows(rows, v, mask, n_live=n_live, active=act)
         self.stats.events_dispatched += n
         self.stats.events_dropped += dropped
@@ -193,17 +215,6 @@ class IngestPipeline:
         self.stats.max_in_flight = max(
             self.stats.max_in_flight,
             sum(t is not None for t in self._tokens))
-        # advance the ring; the next slot's buffers may still back an
-        # in-flight step — the pipeline's only steady-state sync point
-        self._slot = (self._slot + 1) % self.depth
-        self._fill = 0
-        tok = self._tokens[self._slot]
-        if tok is not None:
-            t0 = time.perf_counter()
-            jax.block_until_ready(tok)
-            self.stats.stall_s += time.perf_counter() - t0
-            self._tokens[self._slot] = None
-            self._collect_marks(self._slot)
 
     def _collect_marks(self, slot: int) -> None:
         """Pop the fired sets whose steps the freed slot's token proves done.
@@ -232,7 +243,8 @@ class IngestPipeline:
         t0 = time.perf_counter()
         for i, tok in enumerate(self._tokens):
             if tok is not None:
-                jax.block_until_ready(tok)
+                with jax.profiler.TraceAnnotation("eagr.ring_wait"):
+                    jax.block_until_ready(tok)
                 self._tokens[i] = None
             self._collect_marks(i)
         self.stats.barrier_s += time.perf_counter() - t0

@@ -331,9 +331,6 @@ def test_stats_and_typed_reports(tmp_path):
     assert stats.frontier.get("steps", 0) >= 1
     assert stats.ingest is not None and stats.ingest.events_in == 60
     assert stats.construction is sess.overlay_stats
-    # deprecated alias stays a thin view of the same counters, but warns
-    with pytest.warns(DeprecationWarning, match="stats\\(\\).ingest"):
-        assert sess.ingest_stats is stats.ingest
 
     W, R = np.asarray(sess.writers), np.asarray(sess.readers)
     r0 = int(R[1])

@@ -423,15 +423,3 @@ def test_custom_aggregate_declares_write_arity():
     assert out.shape == (3, 2) and (out >= 0).all()
     with pytest.raises(ValueError, match="value_dim"):
         Query(agg=l2).resolve()  # default scalar window can't feed it
-
-
-def test_ingest_stats_alias_is_deprecated():
-    """``session.ingest_stats`` still answers (a thin view of
-    ``stats().ingest``) but warns — callers should migrate."""
-    g = rmat_graph(60, 260, seed=1)
-    sess = EagrSession(g, ingest_batch=16, ingest_depth=2)
-    sess.register(Query(agg="sum"))
-    sess.update(sess.writers[:8], np.ones(8, np.float32))
-    with pytest.warns(DeprecationWarning, match=r"stats\(\).ingest"):
-        alias = sess.ingest_stats
-    assert alias is sess.stats().ingest

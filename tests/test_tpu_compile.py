@@ -136,3 +136,31 @@ def test_dense_write_step_fits_one_chip(sds):
         sds((B,), jnp.bool_)).compile()
     assert "tpu_custom_call" in compiled.as_text()
     _fits(compiled)
+
+
+def test_kernel_and_scopes_keep_their_trace_names(sds):
+    """The device trace names each operation by its HLO instruction, with
+    its scope path: the kernel's instruction stays ``%segment_agg_call…``
+    (the pattern the benchmark's roofline reader matches), and the lane
+    padding and the kernel call carry their ``eagr.*`` scopes."""
+    import re
+
+    e_pad = N_BLOCKS * E_BLK
+
+    def level(x, seg, tob, fot):
+        return segment_agg_level(x, seg, tob, fot, n_rows=N_NODES,
+                                 n_row_tiles=N_NODES // R_BLK, op="sum",
+                                 interpret=False)
+
+    text = jax.jit(level).lower(
+        sds((e_pad, 1), jnp.float32), sds((e_pad,), jnp.int32),
+        sds((N_BLOCKS,), jnp.int32), sds((N_BLOCKS,), jnp.int32)
+    ).compile().as_text()
+    kernels = [ln.strip() for ln in text.splitlines()
+               if "custom_call_target=\"tpu_custom_call\"" in ln]
+    assert kernels
+    for ln in kernels:
+        assert re.match(r"(ROOT )?%segment_agg_call[.\w]* = ", ln), ln
+        assert "eagr.segment_agg" in ln
+    pads = [ln for ln in text.splitlines() if " pad(" in ln]
+    assert pads and all("eagr.lane_pad" in ln for ln in pads)
