@@ -67,9 +67,11 @@ from repro.core.window import (
 from repro.kernels.segment_agg.ops import (
     E_BLK,
     R_BLK,
+    chunk_blocks,
+    live_extent,
     make_leveled_plan,
     segment_agg_active,
-    segment_agg_level,
+    segment_agg_gather_level,
 )
 
 BACKENDS = ("pallas", "xla", "xla_unrolled")
@@ -183,6 +185,8 @@ class LevelTables(NamedTuple):
     All tables are (L, e_pad) / (L, n_blocks) with padding slots ``seg == -1``
     (source 0, sign 0) so a padded slot contributes nothing on any backend.
     ``touched`` marks, per level, the destination rows the level recomputes.
+    ``live_blocks`` is derived from ``seg`` (``ops.live_extent``): build
+    tables with :func:`level_tables`, which rederives it, never by hand.
     """
 
     seg: jnp.ndarray            # (L, e_pad) int32 destination rows, -1 pad
@@ -191,6 +195,19 @@ class LevelTables(NamedTuple):
     tile_of_block: jnp.ndarray  # (L, n_blocks) int32
     first_of_tile: jnp.ndarray  # (L, n_blocks) int32
     touched: jnp.ndarray        # (L, n_nodes) bool
+    live_blocks: jnp.ndarray    # (L,) int32 per-level live extent in blocks
+
+
+def level_tables(seg, src, sign, tile_of_block, first_of_tile,
+                 touched) -> LevelTables:
+    """``LevelTables`` over the given tables, with ``live_blocks`` derived
+    from ``seg`` on the device. Traceable (the patch program calls it)."""
+    seg = jnp.asarray(seg)
+    return LevelTables(seg=seg, src=jnp.asarray(src), sign=jnp.asarray(sign),
+                       tile_of_block=jnp.asarray(tile_of_block),
+                       first_of_tile=jnp.asarray(first_of_tile),
+                       touched=jnp.asarray(touched),
+                       live_blocks=live_extent(seg))
 
 
 class PlanArrays(NamedTuple):
@@ -252,6 +269,8 @@ class ExecPlan:
     reader_frontier: object | None = None  # frontier.ReaderFrontierIndex —
                                            # read-path twin; invalidated by
                                            # any structural patch
+    _push_live: tuple | None = dataclasses.field(default=None, repr=False)
+                                         # (device leaf, its host copy)
 
     @property
     def n_nodes(self) -> int:
@@ -264,6 +283,29 @@ class ExecPlan:
     @property
     def n_writers(self) -> int:
         return len(self.writer_node)
+
+    def push_live_blocks(self) -> np.ndarray:
+        """Host copy of the push tables' ``live_blocks``: read back once per
+        table set (after a compile, a patch or a restore swaps the leaf),
+        never per step."""
+        leaf = self.arrays.push.live_blocks
+        if self._push_live is None or self._push_live[0] is not leaf:
+            self._push_live = (leaf, np.asarray(jax.device_get(leaf)))
+        return self._push_live[1]
+
+    def dense_sweep_blocks(self, width: int) -> tuple[int, int]:
+        """``(visited, padded)`` edge blocks of one dense push sweep over
+        values ``width`` lanes wide: the blocks the bounded level and chunk
+        loops visit (each live level's extent rounded up to whole chunks on
+        ``pallas``, whole levels on ``xla``), and all ``L x n_blocks``."""
+        live = self.push_live_blocks()
+        L, nb = self.arrays.push.tile_of_block.shape
+        if self.meta.backend == "xla_unrolled":
+            return self.depth * nb, L * nb
+        if self.meta.backend == "pallas":
+            c = chunk_blocks(nb, width, self.meta.bf16)
+            return int(sum(-(-int(b) // c) * c for b in live)), L * nb
+        return int(_live_levels_host(live)) * nb, L * nb
 
 
 def _build_tables(per_level: list[list[tuple[int, int, int]]],
@@ -287,12 +329,8 @@ def _build_tables(per_level: list[list[tuple[int, int, int]]],
         src[l] = lp.layout(l, srcs[l].astype(np.int32), fill=0)
         sign[l] = lp.layout(l, signs[l].astype(np.float32), fill=0.0)
         touched[l, segs[l]] = True
-    return LevelTables(
-        seg=jnp.asarray(lp.seg), src=jnp.asarray(src), sign=jnp.asarray(sign),
-        tile_of_block=jnp.asarray(lp.tile_of_block),
-        first_of_tile=jnp.asarray(lp.first_of_tile),
-        touched=jnp.asarray(touched),
-    )
+    return level_tables(lp.seg, src, sign, lp.tile_of_block,
+                        lp.first_of_tile, touched)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -474,21 +512,26 @@ def _level_reduce(meta: PlanMeta, tables: LevelTables, l, val: jnp.ndarray,
     """Reduce-by-destination of one level's edge contributions gathered from
     ``val`` (n_nodes, F). ``l`` may be traced (fori_loop) or a Python int
     (xla_unrolled). Rows outside the level's touched set are undefined —
-    callers mask. op: 'sum' (signed) | 'max' | 'min'."""
+    callers mask. op: 'sum' (signed) | 'max' | 'min'. On ``pallas`` the
+    gather runs chunk by chunk inside the kernel's chunk loop, which stops
+    at the level's live extent."""
     with jax.named_scope("eagr.level_gather"):
         seg, src, sign = tables.seg[l], tables.src[l], tables.sign[l]
-        tob, fot = tables.tile_of_block[l], tables.first_of_tile[l]
+    if meta.backend == "pallas":
+        with jax.named_scope("eagr.level_gather"):
+            tob, fot = tables.tile_of_block[l], tables.first_of_tile[l]
+            vk = -val if op == "min" else val
+        out = segment_agg_gather_level(
+            vk, src, sign if op == "sum" else None, seg, tob, fot,
+            tables.live_blocks[l], n_rows=meta.n_nodes,
+            n_row_tiles=meta.n_row_tiles,
+            op="max" if op in ("max", "min") else "sum",
+            interpret=meta.interpret, bf16=meta.bf16)
+        return -out if op == "min" else out
+    with jax.named_scope("eagr.level_gather"):
         x = val[src]
         if op == "sum":
             x = x * sign[:, None]
-    if meta.backend == "pallas":
-        kern_op = "max" if op in ("max", "min") else "sum"
-        xk = -x if op == "min" else x
-        out = segment_agg_level(
-            xk, seg, tob, fot,
-            n_rows=meta.n_nodes, n_row_tiles=meta.n_row_tiles,
-            op=kern_op, interpret=meta.interpret, bf16=meta.bf16)
-        return -out if op == "min" else out
     if meta.bf16:
         # match the pallas bf16 semantics: edge values rounded to bfloat16,
         # the segment reduction itself in fp32
@@ -560,14 +603,29 @@ def _row_active(meta: PlanMeta, seg_c) -> jnp.ndarray:
         True, mode="promise_in_bounds")[: meta.n_nodes]
 
 
-def _level_loop(meta: PlanMeta, body, init):
-    """fori_loop over the padded level axis — or the legacy Python unroll over
-    real levels for the 'xla_unrolled' baseline backend."""
+def _live_levels(tables: LevelTables) -> jnp.ndarray:
+    """Traced level-loop bound: one past the last level with a live block
+    (0 when none). Levels past it hold only padding and touch no row."""
+    lb = tables.live_blocks
+    return jnp.max(jnp.where(
+        lb > 0, jnp.arange(1, lb.shape[-1] + 1, dtype=jnp.int32), 0))
+
+
+def _live_levels_host(live: np.ndarray) -> int:
+    """Host twin of :func:`_live_levels` over a host copy of the extents."""
+    hit = np.flatnonzero(np.asarray(live) > 0)
+    return int(hit[-1]) + 1 if hit.size else 0
+
+
+def _level_loop(meta: PlanMeta, body, init, tables: LevelTables):
+    """fori_loop over the levels up to ``tables``' last live one (a traced
+    bound, so churn that moves it never retraces) — or the legacy Python
+    unroll over real levels for the 'xla_unrolled' baseline backend."""
     if meta.backend == "xla_unrolled":
         for l in range(meta.unroll_levels):
             init = body(l, init)
         return init
-    return jax.lax.fori_loop(0, meta.n_levels, body, init)
+    return jax.lax.fori_loop(0, _live_levels(tables), body, init)
 
 
 # ---------------------------------------------------------------- step bodies
@@ -633,7 +691,7 @@ def write_step_sum(meta: PlanMeta, agg: Aggregate, spec: WindowSpec,
             return delta + jnp.where(arrays.push.touched[l][:, None],
                                      contrib, 0.0)
 
-    delta = _level_loop(meta, level, delta)
+    delta = _level_loop(meta, level, delta, arrays.push)
     with jax.named_scope("eagr.level_combine"):
         pao = state.pao + delta
     return EngineState(windows, pao, state.now + 1.0)
@@ -666,7 +724,7 @@ def write_step_extremal(meta: PlanMeta, agg: Aggregate, spec: WindowSpec,
             changed = changed.at[: meta.n_nodes].max(upd)
             return pao, changed
 
-    pao, _ = _level_loop(meta, level, (pao, changed))
+    pao, _ = _level_loop(meta, level, (pao, changed), arrays.push)
     return EngineState(windows, pao, state.now + 1.0)
 
 
@@ -686,7 +744,7 @@ def refresh_pao_step(meta: PlanMeta, agg: Aggregate, spec: WindowSpec,
         with jax.named_scope("eagr.level_combine"):
             return jnp.where(arrays.push.touched[l][:, None], new, pao)
 
-    return _level_loop(meta, level, pao)
+    return _level_loop(meta, level, pao, arrays.push)
 
 
 @jax.named_scope("eagr.read_step")
@@ -697,12 +755,16 @@ def read_step(meta: PlanMeta, agg: Aggregate, arrays: PlanArrays,
     is_pull_target = mask & (decision[reader_nodes] == PULL)
     demand = demand.at[reader_nodes].max(is_pull_target)
 
+    # demand pairs of level l exist only beside pull edges of level l, so
+    # the pull tables' live levels bound both sweeps
+    top = meta.unroll_levels if meta.backend == "xla_unrolled" \
+        else _live_levels(arrays.pull)
+
     def demand_level(i, demand):  # dst level descending
-        l = meta.n_levels - 1 - i if meta.backend != "xla_unrolled" \
-            else meta.unroll_levels - 1 - i
+        l = top - 1 - i
         return demand.at[arrays.demand_src[l]].max(demand[arrays.demand_dst[l]])
 
-    demand = _level_loop(meta, demand_level, demand)
+    demand = _level_loop(meta, demand_level, demand, arrays.pull)
     take = (demand[: meta.n_nodes] & (decision == PULL))[:, None]
     val = state.pao
 
@@ -713,7 +775,7 @@ def read_step(meta: PlanMeta, agg: Aggregate, arrays: PlanArrays,
             return jnp.where(take & arrays.pull.touched[l][:, None],
                              computed, val)
 
-    val = _level_loop(meta, level, val)
+    val = _level_loop(meta, level, val, arrays.pull)
     answers = val[reader_nodes]
     return agg.finalize(answers), answers
 
@@ -868,13 +930,17 @@ def place_plan_arrays(stacked: PlanArrays, s: int,
 
 
 # ------------------------------------------------------------ serialization
+_DERIVED = frozenset({"live_blocks"})  # rederived on restore, never saved
+
+
 def _plan_array_leaves(arrays: PlanArrays):
     """Deterministic (name, leaf) walk of a ``PlanArrays`` pytree with dotted
     names (``push.seg`` ...) — the checkpoint codec's stable key space."""
     for name, val in arrays._asdict().items():
         if isinstance(val, LevelTables):
             for f, sub in val._asdict().items():
-                yield f"{name}.{f}", sub
+                if f not in _DERIVED:
+                    yield f"{name}.{f}", sub
         else:
             yield name, val
 
@@ -933,12 +999,14 @@ def plan_from_snapshot(arrays: dict, objs: dict) -> ExecPlan:
     def put(name):
         return jax.device_put(arrays[f"pa.{name}"])
 
+    def tables(name):
+        return level_tables(**{f: put(f"{name}.{f}")
+                               for f in LevelTables._fields
+                               if f not in _DERIVED})
+
     pa = PlanArrays(
         decision=put("decision"), writer_node=put("writer_node"),
-        push=LevelTables(**{f: put(f"push.{f}")
-                            for f in LevelTables._fields}),
-        pull=LevelTables(**{f: put(f"pull.{f}")
-                            for f in LevelTables._fields}),
+        push=tables("push"), pull=tables("pull"),
         demand_dst=put("demand_dst"), demand_src=put("demand_src"))
     wrob = {int(k): int(v) for k, v in zip(*arrays["wrob"])}
     rnob = {int(k): int(v) for k, v in zip(*arrays["rnob"])}
@@ -1171,8 +1239,13 @@ class EagrEngine:
             -1 if active is None else sum(a.shape[0] for a in active))
         if len(self.frontier_log) > (1 << 20):
             del self.frontier_log[: (1 << 19)]
-        with jax.profiler.TraceAnnotation("eagr.put",
-                                          batch=int(self._now_host)):
+        span = {"batch": int(self._now_host)}
+        if active is None:
+            # the dense sweep's extent, from the host copy of the tables'
+            # live extents: what the bounded level and chunk loops visit
+            span["live_blocks"], span["padded_blocks"] = \
+                self.plan.dense_sweep_blocks(self.agg.pao_dim)
+        with jax.profiler.TraceAnnotation("eagr.put", **span):
             self._put_step(rows, vals, mask, n_live, active)
         self._now_host += 1.0
 

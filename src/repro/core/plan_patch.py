@@ -62,12 +62,14 @@ from repro.core.engine import (
     PlanMeta,
     compile_plan,
     grow_pad,
+    level_tables,
     measure_plan,
 )
 from repro.core.overlay import Overlay
 from repro.kernels.segment_agg.ops import (
     E_BLK,
     R_BLK,
+    live_extent,
     relayout_level,
     scatter_rows,
     scatter_slots,
@@ -161,8 +163,8 @@ def _apply_table(t: LevelTables, p: TablePatch) -> LevelTables:
     tob = scatter_rows(t.tile_of_block, p.row_lvl, p.row_tob)
     fot = scatter_rows(t.first_of_tile, p.row_lvl, p.row_fot)
     touched = scatter_rows(touched, p.row_lvl, p.row_touched)
-    return LevelTables(seg=seg, src=src, sign=sign, tile_of_block=tob,
-                       first_of_tile=fot, touched=touched)
+    # a claim into a tail padding block, or a relayout, moves the extent
+    return level_tables(seg, src, sign, tob, fot, touched)
 
 
 def apply_patch_program(arrays: PlanArrays, prog: PatchProgram) -> PlanArrays:
@@ -441,6 +443,8 @@ class PlanHost:
             for f, dev, want in (("seg", t.seg, m.seg), ("src", t.src, m.src),
                                  ("sign", t.sign, m.sign),
                                  ("touched", t.touched, m.touched),
+                                 ("live_blocks", t.live_blocks,
+                                  np.asarray(live_extent(m.seg))),
                                  ("tile_of_block", t.tile_of_block, th.tob),
                                  ("first_of_tile", t.first_of_tile, th.fot)):
                 if not np.array_equal(np.asarray(dev), want):

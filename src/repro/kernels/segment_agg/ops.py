@@ -332,22 +332,49 @@ def make_leveled_plan(segs: list[np.ndarray], n_rows: int, *,
 CHUNK_BYTES = 1 << 29
 
 
-def _reduce_blocks(x, seg, tob, fot, *, n_rows: int, n_row_tiles: int,
-                   op: str, interpret: bool, bf16: bool) -> jnp.ndarray:
-    """Kernel over the edge blocks of ``x`` (n_blocks * E_BLK, F) in chunks
-    of at most ``CHUNK_BYTES`` of padded values; returns (n_rows, F)."""
+def live_extent(seg: jnp.ndarray) -> jnp.ndarray:
+    """(L,) int32 live extent of stacked (L, e_pad) slot tables: per level,
+    one past the last edge block holding a slot with ``seg >= 0``, or 0 for
+    a level with none. Blocks past it hold only padding, so a sweep that
+    stops there is exact. Computed on device from ``seg`` alone: a slot
+    claim may land in a tail padding block and move the extent, so callers
+    rederive it wherever ``seg`` changes and never track it by hand."""
+    L, e_pad = seg.shape
+    nb = e_pad // E_BLK
+    live = jnp.any(jnp.reshape(seg, (L, nb, E_BLK)) >= 0, axis=-1)
+    return jnp.max(jnp.where(live, jnp.arange(1, nb + 1, dtype=jnp.int32), 0),
+                   axis=1).astype(jnp.int32)
+
+
+def chunk_blocks(n_blocks: int, f: int, bf16: bool) -> int:
+    """Edge blocks per kernel call of a level of ``n_blocks`` blocks whose
+    values are ``f`` lanes wide: the largest power of two that divides
+    ``n_blocks`` and keeps the lane-padded chunk within ``CHUNK_BYTES``."""
+    f_pad = -(-f // F_BLK) * F_BLK
+    itemsize = 2 if bf16 else 4
+    fit = max(1, CHUNK_BYTES // (E_BLK * f_pad * itemsize))
+    return min(n_blocks & -n_blocks, 1 << (fit.bit_length() - 1))
+
+
+def _reduce_blocks(edges, f: int, seg, tob, fot, live=None, *, n_rows: int,
+                   n_row_tiles: int, op: str, interpret: bool,
+                   bf16: bool) -> jnp.ndarray:
+    """Kernel over the edge blocks of one level in chunks of at most
+    ``CHUNK_BYTES`` of padded values; returns (n_rows, f). ``edges(start,
+    size)`` yields the (size, f) values of slots ``[start, start + size)``,
+    so each chunk's values are made (sliced or gathered) inside its own
+    iteration. ``live`` (traced, optional) is the level's live extent in
+    blocks: the chunks past it hold only padding and are skipped."""
     n_blocks = tob.shape[0]
-    F = x.shape[1]
-    f_pad = -(-F // F_BLK) * F_BLK
+    f_pad = -(-f // F_BLK) * F_BLK
     dt = jnp.bfloat16 if bf16 else jnp.float32
-    fit = max(1, CHUNK_BYTES // (E_BLK * f_pad * jnp.dtype(dt).itemsize))
-    c = min(n_blocks & -n_blocks, 1 << (fit.bit_length() - 1))
+    c = chunk_blocks(n_blocks, f, bf16)
 
     def chunk(i, acc):
         take = lambda a, n: jax.lax.dynamic_slice_in_dim(a, i * n, n)
+        xc = edges(i * (c * E_BLK), c * E_BLK)
         with jax.named_scope("eagr.lane_pad"):
-            xc = jnp.pad(take(x, c * E_BLK).astype(dt),
-                         ((0, 0), (0, f_pad - F)))
+            xc = jnp.pad(xc.astype(dt), ((0, 0), (0, f_pad - f)))
         with jax.named_scope("eagr.segment_agg"):
             return segment_agg_call(xc, take(seg, c * E_BLK), take(tob, c),
                                     take(fot, c), acc, op=op,
@@ -355,7 +382,9 @@ def _reduce_blocks(x, seg, tob, fot, *, n_rows: int, n_row_tiles: int,
 
     with jax.named_scope("eagr.lane_pad"):
         acc = jnp.zeros((n_row_tiles * R_BLK, f_pad), jnp.float32)
-    if n_blocks == c:
+    if live is not None:
+        acc = jax.lax.fori_loop(0, (live + (c - 1)) // c, chunk, acc)
+    elif n_blocks == c:
         acc = chunk(0, acc)
     else:
         acc = jax.lax.fori_loop(0, n_blocks // c, chunk, acc)
@@ -363,7 +392,11 @@ def _reduce_blocks(x, seg, tob, fot, *, n_rows: int, n_row_tiles: int,
     # accumulator's (8, 128) tiling, and on TPU every (n_rows, F) array fed
     # by it (the level-loop carry) then pads F to 128 lanes in HBM
     with jax.named_scope("eagr.lane_pad"):
-        return acc.T[:F, :n_rows].T
+        return acc.T[:f, :n_rows].T
+
+
+def _slices(x: jnp.ndarray):
+    return lambda start, size: jax.lax.dynamic_slice_in_dim(x, start, size)
 
 
 def segment_agg_level(x: jnp.ndarray, seg: jnp.ndarray, tob: jnp.ndarray,
@@ -381,8 +414,33 @@ def segment_agg_level(x: jnp.ndarray, seg: jnp.ndarray, tob: jnp.ndarray,
     ``bf16`` streams edge values into VMEM as bfloat16 (2x block headroom);
     the kernels cast per block and accumulate in fp32 either way.
     """
-    return _reduce_blocks(x, seg, tob, fot, n_rows=n_rows,
-                          n_row_tiles=n_row_tiles, op=op,
+    return _reduce_blocks(_slices(x), x.shape[1], seg, tob, fot,
+                          n_rows=n_rows, n_row_tiles=n_row_tiles, op=op,
+                          interpret=interpret, bf16=bf16)
+
+
+def segment_agg_gather_level(val: jnp.ndarray, src: jnp.ndarray,
+                             sign: jnp.ndarray | None, seg: jnp.ndarray,
+                             tob: jnp.ndarray, fot: jnp.ndarray,
+                             live_blocks: jnp.ndarray, *, n_rows: int,
+                             n_row_tiles: int, op: str = "sum",
+                             interpret: bool = True,
+                             bf16: bool = False) -> jnp.ndarray:
+    """``segment_agg_level`` of the edge values ``val[src] * sign`` (no
+    sign: ``val[src]``), gathered one chunk at a time inside the chunk loop
+    and only up to the level's live extent ``live_blocks``: the sweep's
+    gather, lane padding and kernel grid all stop at the last live chunk.
+    ``val`` is (n_nodes, F); ``src``/``sign``/``seg`` are the level's
+    (e_pad,) slot tables."""
+    def edges(start, size):
+        with jax.named_scope("eagr.level_gather"):
+            x = val[jax.lax.dynamic_slice_in_dim(src, start, size)]
+            if sign is not None:
+                x = x * jax.lax.dynamic_slice_in_dim(sign, start, size)[:, None]
+            return x
+
+    return _reduce_blocks(edges, val.shape[1], seg, tob, fot, live_blocks,
+                          n_rows=n_rows, n_row_tiles=n_row_tiles, op=op,
                           interpret=interpret, bf16=bf16)
 
 
@@ -403,6 +461,6 @@ def segment_agg_active(x: jnp.ndarray, seg: jnp.ndarray, tob: jnp.ndarray, *,
     """
     fot = jnp.concatenate([jnp.ones((1,), jnp.int32),
                            (tob[1:] != tob[:-1]).astype(jnp.int32)])
-    return _reduce_blocks(x, seg, tob, fot, n_rows=n_rows,
-                          n_row_tiles=n_row_tiles, op=op,
+    return _reduce_blocks(_slices(x), x.shape[1], seg, tob, fot,
+                          n_rows=n_rows, n_row_tiles=n_row_tiles, op=op,
                           interpret=interpret, bf16=bf16)

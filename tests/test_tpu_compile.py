@@ -1,6 +1,7 @@
 """Compile rehearsal for a TPU v5e: the main-path kernels and the dense write
-step at the plan shapes of a 1M-node / 10M-edge power-law overlay, compiled
-for a described (not attached) chip. Nothing runs; a compile that passes
+step (its level and chunk loops bounded by the tables' live extents) at the
+plan shapes of a 1M-node / 10M-edge power-law overlay, compiled for a
+described (not attached) chip. Nothing runs; a compile that passes
 means the chip's compiler accepts the program and it fits device memory.
 
 This is the only file that describes the chip. The topology is described
@@ -8,6 +9,7 @@ inside a module fixture, never at import, so every xdist worker collects the
 same tests and only the worker given this file loads libtpu.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -23,7 +25,11 @@ from repro.core.engine import (
     _write_body_sum,
 )
 from repro.core.window import WindowSpec, WindowState
-from repro.kernels.segment_agg.ops import segment_agg_active, segment_agg_level
+from repro.kernels.segment_agg.ops import (
+    segment_agg_active,
+    segment_agg_gather_level,
+    segment_agg_level,
+)
 from repro.kernels.segment_agg.segment_agg import E_BLK, R_BLK
 
 # Padded plan of the continuous count group over powerlaw_graph(1M, 10M,
@@ -90,6 +96,29 @@ def test_segment_agg_level_compiles_dense(sds, op, bf16):
 
 
 @pytest.mark.parametrize("op,bf16", KERNELS)
+def test_segment_agg_gather_level_compiles_bounded(sds, op, bf16):
+    """The dense path's entry: the gather of each chunk's edge values runs
+    inside the chunk loop, whose trip count is the traced live extent."""
+    e_pad = N_BLOCKS * E_BLK
+
+    def level(val, src, sign, seg, tob, fot, live):
+        return segment_agg_gather_level(
+            val, src, sign if op == "sum" else None, seg, tob, fot, live,
+            n_rows=N_NODES, n_row_tiles=N_NODES // R_BLK, op=op,
+            interpret=False, bf16=bf16)
+
+    compiled = jax.jit(level).lower(
+        sds((N_NODES, 1), jnp.float32), sds((e_pad,), jnp.int32),
+        sds((e_pad,), jnp.float32), sds((e_pad,), jnp.int32),
+        sds((N_BLOCKS,), jnp.int32), sds((N_BLOCKS,), jnp.int32),
+        sds((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert " while(" in text
+    _fits(compiled)
+
+
+@pytest.mark.parametrize("op,bf16", KERNELS)
 def test_segment_agg_active_compiles(sds, op, bf16):
     k_pad = ACTIVE_BLOCKS * E_BLK
 
@@ -116,7 +145,8 @@ def test_dense_write_step_fits_one_chip(sds):
         return LevelTables(
             sds((L, ep), jnp.int32), sds((L, ep), jnp.int32),
             sds((L, ep), jnp.float32), sds((L, n_blocks), jnp.int32),
-            sds((L, n_blocks), jnp.int32), sds((L, N_NODES), jnp.bool_))
+            sds((L, n_blocks), jnp.int32), sds((L, N_NODES), jnp.bool_),
+            sds((L,), jnp.int32))
 
     arrays = PlanArrays(
         sds((N_NODES,), jnp.int32), sds((N_WRITERS,), jnp.int32),
@@ -134,7 +164,13 @@ def test_dense_write_step_fits_one_chip(sds):
         meta, make_aggregate("count"), spec, arrays, state,
         sds((B,), jnp.int32), sds((B,), jnp.float32),
         sds((B,), jnp.bool_)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the edge values are gathered one chunk at a time inside the bounded
+    # chunk loop: no gather ever makes a whole padded level's values
+    gathers = [int(n) for n in re.findall(
+        r"= f32\[(\d+)(?:,1)?\]\{[^}]*\} gather\(", text)]
+    assert gathers and max(gathers) < N_BLOCKS * E_BLK
     _fits(compiled)
 
 

@@ -236,6 +236,38 @@ def test_tracing_changes_no_answer_and_no_program(tmp_path):
     assert got[0] == got[1]
 
 
+@pytest.mark.parametrize("mode", ["0", "1"])
+def test_dense_puts_carry_the_sweeps_extent(tmp_path, monkeypatch, mode):
+    """Each dense write step's ``eagr.put`` (inside ``eagr.dispatch``)
+    carries the blocks its bounded sweep visits and the plan's padded
+    blocks, from the host copy of the extents; sparse steps carry neither."""
+    monkeypatch.setenv("EAGR_SPARSE_WRITE", mode)
+    s, h = _session()
+    W = np.asarray(s.writers)
+    eng = h.group.engine
+    s.update(W[:32])
+    s.flush()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        s.update(W[16:48])
+        s.flush()
+    finally:
+        jax.profiler.stop_trace()
+    spans = _spans(str(tmp_path))
+    puts = [p for d in spans if d[0] == "eagr.dispatch"
+            for p in _inside(d, spans, "eagr.put")]
+    assert len(puts) == 2
+    live, padded = eng.plan.dense_sweep_blocks(eng.agg.pao_dim)
+    L, nb = eng.plan.arrays.push.tile_of_block.shape
+    for p in puts:
+        if mode == "0":
+            assert (p[3]["live_blocks"], p[3]["padded_blocks"]) == \
+                (live, padded)
+            assert 0 < live < padded == L * nb
+        else:
+            assert "live_blocks" not in p[3] and "padded_blocks" not in p[3]
+
+
 # ------------------------------------------------------------------ counters
 @pytest.mark.parametrize("cap,overflow", [(2, True), (4096, False)])
 def test_alert_stats_count_dense_fallback_readbacks(monkeypatch, cap,
